@@ -966,15 +966,28 @@ def bench_fleet(out_path: str = "BENCH_serving.json",
 
     Gate (``--quick``): stall-scenario rps at 2 replicas >= 1.5x the
     1-replica rps, best paired trial.
+
+    The child runs on the CPU backend and its numbers are CPU numbers. On
+    a machine with an accelerator the parent already holds the device
+    (earlier benches initialised JAX), so a child could only fall back to
+    the CPU: this refuses instead of reporting that as a device result.
     """
     import subprocess
     import sys
 
+    import jax
+
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise SystemExit(
+            f"bench_fleet: this process holds the {platform} device, and "
+            "its child would run on the CPU; the fleet scaling harness is "
+            "a CPU behaviour check (run it with JAX_PLATFORMS=cpu)")
     here = os.path.dirname(os.path.abspath(__file__))
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=8").strip()
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (os.path.join(here, "..", "src"),
                     env.get("PYTHONPATH")) if p)
@@ -994,6 +1007,7 @@ def bench_fleet(out_path: str = "BENCH_serving.json",
     rep = json.loads(lines[-1])
     ratio = rep["stall"]["ratio"]
     entry = {
+        "platform": "cpu",
         "devices": rep["devices"],
         "requests": rep["requests"],
         "stall_rps_1_replica": rep["stall"]["rps_1_replica"],
@@ -1027,10 +1041,9 @@ def bench_kernels():
     f_ref = jax.jit(lambda q, k, v: ref.attention_ref(q, k, v))
     f_ref(q, k, v).block_until_ready()
     t_ref = _time(lambda: f_ref(q, k, v).block_until_ready())
-    ops.set_backend("interpret")
-    out = ops.flash_attention(q, k, v)
+    with ops.use_backend("interpret"):
+        out = ops.flash_attention(q, k, v)
     ok = bool(jnp.allclose(out, ref.attention_ref(q, k, v), atol=2e-5))
-    ops.set_backend("ref")
     row("kernel_flash_attention_oracle", t_ref, f"interpret_allclose={ok}")
 
     a = jnp.asarray(rng.uniform(0.5, 0.99, (1, 256, 512)), jnp.float32)
@@ -1038,10 +1051,9 @@ def bench_kernels():
     f_rg = jax.jit(ref.rglru_ref)
     f_rg(a, b).block_until_ready()
     t_rg = _time(lambda: f_rg(a, b).block_until_ready())
-    ops.set_backend("interpret")
-    h, _ = ops.rglru_scan(a, b)
+    with ops.use_backend("interpret"):
+        h, _ = ops.rglru_scan(a, b)
     ok = bool(jnp.allclose(h, ref.rglru_ref(a, b), atol=1e-5))
-    ops.set_backend("ref")
     row("kernel_rglru_oracle", t_rg, f"interpret_allclose={ok}")
 
     x = jnp.asarray(rng.normal(size=(4, 128, 256)), jnp.float32)
@@ -1049,9 +1061,9 @@ def bench_kernels():
     f_gmm = jax.jit(ref.gmm_ref)
     f_gmm(x, w).block_until_ready()
     t_g = _time(lambda: f_gmm(x, w).block_until_ready())
-    ops.set_backend("interpret")
-    ok = bool(jnp.allclose(ops.gmm(x, w), ref.gmm_ref(x, w), atol=2e-4))
-    ops.set_backend("ref")
+    with ops.use_backend("interpret"):
+        out = ops.gmm(x, w)
+    ok = bool(jnp.allclose(out, ref.gmm_ref(x, w), atol=2e-4))
     row("kernel_gmm_oracle", t_g, f"interpret_allclose={ok}")
 
 
@@ -1096,6 +1108,8 @@ def main(argv=None) -> None:
                          "(chaos vs fault-free twin); exit nonzero if "
                          "completion, token identity, or goodput regresses")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     print("name,us_per_call,derived")
     if args.quick or args.chaos_quick:
         smokes = [("robustness", bench_robustness)] if args.chaos_quick \

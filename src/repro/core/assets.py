@@ -2,9 +2,11 @@
 
 Every assigned architecture is registered as a MAX asset (the paper's "30+
 wrapped models" catalogue, here 12+). Builders default to the REDUCED
-config (same family, 2 layers) with seeded random weights so every asset is
-buildable and servable on CPU; ``smoke=False`` selects the full
-production config (dry-run / pod deployment only).
+config (same family, 2 layers, float32 weights) with seeded random weights
+so every asset is buildable and servable on CPU. ``smoke=False`` builds the
+published widths with bfloat16 weights, which is how a model is served on
+a chip: ``chip_smoke.py`` deploys qwen3-4b this way on one TPU v5e. A
+model must fit the device it is placed on; nothing here shards it.
 
 Wrapper types mirror the paper's demo zoo:
 - TextGenerationWrapper     (LLM assets; object-detector analogue of "apply
@@ -66,8 +68,13 @@ class _EngineWrapper(MAXModelWrapper):
         if smoke and cfg.name in ASSIGNED:
             cfg = reduce_for_smoke(cfg)
         self.cfg = cfg
-        self.model = build_model(cfg)
-        self.params = self.model.init(jax.random.PRNGKey(seed))
+        # full width serves bf16 weights: f32 doubles the bytes every decode
+        # step reads, and qwen3-4b in f32 does not fit a 16 GB chip
+        self.model = build_model(
+            cfg, param_dtype=jnp.float32 if smoke else jnp.bfloat16)
+        # jitted: at full width an eager init would hold f32 copies of
+        # whole layer stacks next to the weights already built
+        self.params = jax.jit(self.model.init)(jax.random.PRNGKey(seed))
         self.engine = GenerationEngine(self.model, self.params,
                                        max_batch=max_batch, max_seq=max_seq,
                                        eos_id=TOKENIZER.eos_id,
@@ -77,6 +84,12 @@ class _EngineWrapper(MAXModelWrapper):
                                        prefix_cache=prefix_cache,
                                        prefix_cache_pages=prefix_cache_pages)
         self.MODEL_META_DATA = asset.metadata
+
+    def place(self, device) -> None:
+        """Commit weights and KV cache to ``device`` (replica placement):
+        every program the engine dispatches then runs there."""
+        self.engine.place(device)
+        self.params = self.engine.params
 
     def _result(self, tokens: List[int], prompt_len: int) -> GenerationResult:
         return GenerationResult(tokens=list(tokens), prompt_len=prompt_len,

@@ -158,35 +158,34 @@ class ReplicaSet(InferenceService):
 
     def _build_on_slice(self, sl: Optional[ReplicaSlice]
                         ) -> MAXModelWrapper:
-        """Build one replica's wrapper with its parameters placed on the
-        slice's lead device (compute follows its operands, so the
-        replica's decode runs there too). On a single-device platform the
-        bind folds every slice onto that device — placement is then a
-        no-op, which is exactly the CI fallback the forced-host-device
-        job exists to avoid."""
+        """Build one replica's wrapper on the slice's lead device and
+        commit its weights and KV cache there (compute follows committed
+        operands, so the replica's decode runs there too). A slice that
+        cannot be bound raises: a replica never silently lands on another
+        device. On a single-device platform every slice binds that device
+        (an oversubscribed placement)."""
         dev = None
-        if sl is not None:
-            try:
-                import jax
-                dev = sl.bind(jax.devices())[0]
-            except Exception:
-                dev = None
-        if dev is None:
-            return self._factory()
-        import jax
-        with jax.default_device(dev):
-            return self._factory()
+        if sl is None:
+            wrapper = self._factory()
+        else:
+            import jax
+            dev = sl.bind(jax.devices())[0]
+            with jax.default_device(dev):
+                wrapper = self._factory()
+        if not wrapper.supports_generation():
+            raise ValueError(
+                f"{wrapper.metadata.id!r} does not implement the "
+                "generation protocol; replica groups require the batched "
+                "service")
+        if dev is not None:
+            wrapper.place(dev)
+        return wrapper
 
     def _spawn(self, index: int) -> _Replica:
         name = f"r{index}"
         sl = self._placement.slices[index] \
             if index < len(self._placement.slices) else None
         wrapper = self._build_on_slice(sl)
-        if not wrapper.supports_generation():
-            raise ValueError(
-                f"{wrapper.metadata.id!r} does not implement the "
-                "generation protocol; replica groups require the batched "
-                "service")
         kw: Dict[str, Any] = dict(self._batched_kw)
         kw["faults"] = self._fault_for(index)
         for k in ("job_ttl_s", "trace", "trace_buffer", "slow_trace_ms"):

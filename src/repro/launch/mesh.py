@@ -15,7 +15,7 @@ rules.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
 
 SINGLE_POD_SHAPE = (16, 16)
 SINGLE_POD_AXES = ("data", "model")
@@ -29,6 +29,14 @@ ICI_BW = 50e9                     # bytes/s per link
 HBM_PER_CHIP = 16 * 2**30         # 16 GiB
 
 
+def _auto_mesh(shape, axes, devices) -> Mesh:
+    """The one place meshes are built: every axis is Auto, so GSPMD
+    partitions and ``with_sharding_constraint`` accepts the axes
+    (``jax.make_mesh`` defaults to Explicit axes since jax 0.9)."""
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = MULTI_POD_SHAPE if multi_pod else SINGLE_POD_SHAPE
     axes = MULTI_POD_AXES if multi_pod else SINGLE_POD_AXES
@@ -40,7 +48,7 @@ def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
         raise RuntimeError(
             f"need {n} devices for mesh {shape}, have {len(devices)} — "
             "run under launch/dryrun.py (forces 512 host devices)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
 
 
 def make_test_mesh(shape=(2, 4), axes=("data", "model")) -> Mesh:
@@ -48,7 +56,7 @@ def make_test_mesh(shape=(2, 4), axes=("data", "model")) -> Mesh:
     n = 1
     for s in shape:
         n *= s
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return _auto_mesh(shape, axes, jax.devices()[:n])
 
 
 def num_chips(mesh: Mesh) -> int:

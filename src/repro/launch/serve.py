@@ -8,9 +8,10 @@
 concurrent HTTP predicts coalesce into engine decode batches), or ``auto``
 (batched for generative wrappers, sync otherwise).
 
-Deployed assets use reduced (CPU-runnable) configs by default; on a pod the
-same launcher would pass ``smoke=False`` build kwargs and a mesh slice per
-deployment (core/deployment.py).
+Deployed assets use reduced (CPU-runnable) configs. Full width is served
+by passing ``build_kw={"smoke": False, ...}`` to ``MAXServer``, as
+``chip_smoke.py`` does for qwen3-4b on one TPU v5e. Run this launcher with
+``JAX_PLATFORMS=cpu`` on a machine without an accelerator.
 """
 
 from __future__ import annotations
@@ -35,6 +36,9 @@ def main():
     ap.add_argument("--duration", type=float, default=None,
                     help="serve for N seconds then exit (default: forever)")
     args = ap.parse_args()
+
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
 
     import repro.core.assets  # noqa: F401 — populate the exchange
     from repro.core import EXCHANGE, MAXServer

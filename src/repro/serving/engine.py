@@ -105,6 +105,8 @@ class GenerationEngine:
         self.model = model
         self.params = params
         self.cfg = model.cfg
+        # device the engine's state is committed to (None: JAX's default)
+        self._device = None
         self.max_batch = max_batch
         self.max_seq = max_seq
         self.eos_id = eos_id
@@ -211,6 +213,16 @@ class GenerationEngine:
                 per_entry = int(np.prod(k.shape[3:])) * k.dtype.itemsize
                 return 2 * k.shape[0] * per_entry
         return 0
+
+    def place(self, device) -> None:
+        """Commit params, KV cache and the next-token buffer to ``device``.
+        Jitted programs run where their committed operands live, so every
+        later prefill/decode of this engine runs on ``device``; ``reset``
+        re-commits the state it rebuilds."""
+        self._device = device
+        self.params = jax.device_put(self.params, device)
+        self._cache = jax.device_put(self._cache, device)
+        self._next_tok = jax.device_put(self._next_tok, device)
 
     # -- jitted internals ---------------------------------------------------
 
@@ -1051,6 +1063,9 @@ class GenerationEngine:
         self._prompt_lens = np.zeros((max_batch,), np.int32)
         self._prefill_lens = np.zeros((max_batch,), np.int32)
         self._next_tok = jnp.zeros((max_batch,), jnp.int32)
+        if self._device is not None:
+            self._cache = jax.device_put(self._cache, self._device)
+            self._next_tok = jax.device_put(self._next_tok, self._device)
         self._prefill_jit = {}
         self._decode = jax.jit(self._decode_impl)
         self._chunk_jit = {}

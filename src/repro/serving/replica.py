@@ -49,13 +49,10 @@ _POD_ROWS_RE = re.compile(r"pod(\d+)/rows(\d+)(?:-(\d+))?$")
 
 
 def live_device_count() -> int:
-    """Number of addressable devices right now (1 when jax is absent or
-    uninitializable — the degenerate placement still works)."""
-    try:
-        import jax
-        return max(1, jax.device_count())
-    except Exception:
-        return 1
+    """Number of addressable devices right now. A JAX that cannot start
+    raises here: a placement is never validated against a guessed count."""
+    import jax
+    return jax.device_count()
 
 
 @dataclass(frozen=True)

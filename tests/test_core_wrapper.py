@@ -118,3 +118,16 @@ def test_sentiment_envelope_matches_paper_fig3():
         d = row[0]
         assert set(d) == {"positive", "negative"}
         assert abs(d["positive"] + d["negative"] - 1.0) < 1e-5
+
+
+@pytest.mark.parametrize("smoke,dtype", [(True, "float32"),
+                                         (False, "bfloat16")])
+def test_full_width_builds_bf16_weights(smoke, dtype):
+    """Reduced builds keep f32 weights; the full-width build serves bf16
+    (the demo config is small at either setting)."""
+    w = EXCHANGE.get("max-sentiment").build(smoke=smoke, max_seq=32,
+                                            max_batch=1)
+    layers = w.params["layers"]
+    for leaf in (w.params["embed"], layers["attn"]["wq"],
+                 layers["mlp"]["w_up"]):
+        assert str(leaf.dtype) == dtype

@@ -14,12 +14,6 @@ ARCHS = ["qwen3-4b", "qwen3-moe-235b-a22b", "recurrentgemma-9b",
          "rwkv6-7b", "whisper-large-v3", "internvl2-2b"]
 
 
-@pytest.fixture(autouse=True)
-def _restore_backend():
-    yield
-    ops.set_backend("ref")
-
-
 @pytest.mark.parametrize("name", ARCHS)
 def test_forward_kernel_backend_matches_ref(name, rng):
     cfg = reduce_for_smoke(ASSIGNED[name])
@@ -34,10 +28,10 @@ def test_forward_kernel_backend_matches_ref(name, rng):
         batch["image_embeds"] = jax.random.normal(
             rng, (B, cfg.num_image_tokens, cfg.d_model), jnp.float32)
 
-    ops.set_backend("ref")
-    ref_logits, _ = model.forward(params, batch)
-    ops.set_backend("interpret")
-    k_logits, _ = model.forward(params, batch)
+    with ops.use_backend("ref"):
+        ref_logits, _ = model.forward(params, batch)
+    with ops.use_backend("interpret"):
+        k_logits, _ = model.forward(params, batch)
     err = float(jnp.max(jnp.abs(ref_logits - k_logits)))
     assert err < 5e-4, f"{name}: kernel backend diverges, err={err}"
 
@@ -57,8 +51,8 @@ def test_decode_kernel_backend_matches_ref(name, rng):
             outs.append(lg)
         return jnp.stack(outs)
 
-    ops.set_backend("ref")
-    a = run()
-    ops.set_backend("interpret")
-    b = run()
+    with ops.use_backend("ref"):
+        a = run()
+    with ops.use_backend("interpret"):
+        b = run()
     assert float(jnp.max(jnp.abs(a - b))) < 5e-4

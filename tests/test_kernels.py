@@ -48,8 +48,7 @@ def test_flash_attention_sweep(B, H, KV, Sq, Skv, hd, causal, window, dtype):
 
 def test_flash_attention_padding_path():
     """ops wrapper pads ragged seq lens; padded kv must be masked."""
-    ops.set_backend("interpret")
-    try:
+    with ops.use_backend("interpret"):
         q = _rand((1, 4, 100, 64), 1)
         k = _rand((1, 2, 100, 64), 2)
         v = _rand((1, 2, 100, 64), 3)
@@ -58,8 +57,6 @@ def test_flash_attention_padding_path():
             expect = ref.attention_ref(q, k, v, causal=causal)
             np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                        atol=2e-5, rtol=1e-4)
-    finally:
-        ops.set_backend("ref")
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -134,13 +131,26 @@ def test_gmm_kernel_sweep(E, C, d, f, dtype):
 
 
 def test_ops_padding_gmm():
-    ops.set_backend("interpret")
-    try:
+    with ops.use_backend("interpret"):
         x = _rand((3, 60, 100), 1)
         w = _rand((3, 100, 300), 2)
         out = ops.gmm(x, w)
         np.testing.assert_allclose(np.asarray(out),
                                    np.asarray(ref.gmm_ref(x, w)),
                                    atol=2e-5, rtol=1e-4)
-    finally:
-        ops.set_backend("ref")
+
+
+@pytest.mark.parametrize("platform,expect", [("tpu", "pallas"),
+                                             ("cpu", "ref"),
+                                             ("gpu", "ref")])
+def test_backend_follows_platform(monkeypatch, platform, expect):
+    """Undecided, the backend is read from JAX's default backend on first
+    use; a forced backend is restored by use_backend on exit."""
+    monkeypatch.setattr(ops, "_BACKEND", None)
+    monkeypatch.setattr(jax, "default_backend", lambda: platform)
+    assert ops.get_backend() == expect
+    with ops.use_backend("interpret"):
+        assert ops.get_backend() == "interpret"
+    assert ops.get_backend() == expect
+    with pytest.raises(ValueError):
+        ops.set_backend("mosaic")

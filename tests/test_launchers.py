@@ -30,3 +30,26 @@ def test_serve_launcher():
     assert proc.returncode == 0, proc.stderr[-1500:]
     assert "deployed max-sentiment" in proc.stdout
     assert "12 assets registered" in proc.stdout
+
+
+def test_compile_cache_location(monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR wins and nothing is set in code; without
+    it the cache goes to one fixed, gitignored directory of the checkout."""
+    import pathlib
+
+    import jax
+    from repro.launch.compile_cache import use_compile_cache
+
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    prev = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+        assert use_compile_cache() == "/elsewhere/cache"
+        assert jax.config.jax_compilation_cache_dir == prev
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = use_compile_cache()
+        assert path == str(repo / ".jax_cache") == use_compile_cache()
+        assert jax.config.jax_compilation_cache_dir == path
+        assert ".jax_cache/" in (repo / ".gitignore").read_text().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)

@@ -154,11 +154,8 @@ def test_paged_chunk_interpret_backend_matches_ref(sentiment):
         return firsts, toks[emitted].tolist()
 
     want = run()
-    ops.set_backend("interpret")
-    try:
+    with ops.use_backend("interpret"):
         got = run()
-    finally:
-        ops.set_backend("ref")
     assert got == want
 
 

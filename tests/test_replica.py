@@ -166,6 +166,56 @@ def test_fleet_batch_spreads_over_replicas(fleet):
     assert all(e["status"] == "ok" for e in envs)
 
 
+def test_replica_state_committed_to_its_slice_device():
+    """Each replica's weights and KV cache are committed to the device its
+    slice binds, so its programs run there (also after an engine reset)."""
+    import jax
+    asset = EXCHANGE.get(MODEL)
+    rs = ReplicaSet(lambda: asset.build(**BUILD_KW), replicas=2,
+                    batch_window_s=0.01)
+    try:
+        env = rs.predict({"text": "placed", "max_new_tokens": 3})
+        assert env["status"] == "ok"
+        devices = jax.devices()
+        for rep in rs._replicas:
+            want = rep.slice_.bind(devices)[0]
+            eng = rep.service.wrapper.engine
+            eng.reset()
+            for leaf in jax.tree.leaves((eng.params, eng._cache,
+                                         eng._next_tok)):
+                assert leaf.committed and leaf.devices() == {want}
+    finally:
+        rs.close()
+
+
+def test_unbindable_slice_raises_instead_of_default_device():
+    """A slice that cannot be bound fails the build; the replica is never
+    built on some other device."""
+    from repro.serving.replica import MeshPlacement, ReplicaSlice
+    asset = EXCHANGE.get(MODEL)
+    bad = MeshPlacement(spec="devices:4096", slices=(
+        ReplicaSlice(label="devices:4096", chips=(4096,)),))
+    with pytest.raises(IndexError):
+        ReplicaSet(lambda: asset.build(**BUILD_KW), replicas=1,
+                   placement=bad)
+
+
+def test_bench_fleet_refuses_when_parent_holds_an_accelerator(monkeypatch):
+    """The fleet harness child would run on the CPU while the parent holds
+    the chip: it refuses instead of reporting CPU numbers."""
+    import importlib.util
+    import pathlib
+
+    import jax
+    path = pathlib.Path(__file__).resolve().parents[1] / "benchmarks/run.py"
+    spec = importlib.util.spec_from_file_location("bench_run", path)
+    bench = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(bench)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(SystemExit, match="tpu"):
+        bench.bench_fleet(out_path="/nonexistent/BENCH.json", quick=True)
+
+
 # -- scaling: up, and drain-without-loss down --------------------------------
 
 def test_scale_up_then_drain_down_loses_nothing():
