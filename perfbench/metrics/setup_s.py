@@ -1,0 +1,5 @@
+"""setup_s: process start to the first due request, compiles included."""
+
+
+def read(run):
+    return run.setup_s
