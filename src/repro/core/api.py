@@ -78,6 +78,7 @@ from repro.serving.qos import PRIORITIES, AdmissionError
 from repro.serving.replica import (
     MeshSliceError, live_device_count, parse_mesh_slice,
 )
+from repro.serving.tracing import clock_pair
 
 API_VERSION = "v1"          # of the back-compat surface
 API_VERSIONS = ("v1", "v2")
@@ -821,8 +822,11 @@ class MAXServer:
                 events.extend(tracer.to_chrome(pid=pid,
                                                process_name=asset_id))
         # the Chrome trace-event container format: an object with a
-        # traceEvents array loads directly in Perfetto / chrome://tracing
-        return 200, {"traceEvents": events, "displayTimeUnit": "ms"}
+        # traceEvents array loads directly in Perfetto / chrome://tracing.
+        # The clock pair maps its timestamps onto a JAX profiler trace of
+        # the same process (whose host spans carry the same max.* names)
+        return 200, {"traceEvents": events, "displayTimeUnit": "ms",
+                     "metadata": {"clocks": clock_pair()}}
 
     def _h_deploy_v2(self, ctx) -> Tuple[int, Dict[str, Any]]:
         body = ctx.body if isinstance(ctx.body, dict) else {}

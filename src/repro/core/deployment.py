@@ -34,7 +34,7 @@ from repro.core.service import InferenceService, Job, make_service
 from repro.core.wrapper import MAXModelWrapper
 from repro.serving.metrics import MetricsRegistry
 from repro.serving.replica import live_device_count, parse_mesh_slice
-from repro.serving.tracing import now as _now
+from repro.serving.tracing import gc_stats, now as _now
 from repro.serving.qos import QoSConfig
 
 
@@ -125,6 +125,16 @@ class DeploymentManager:
         # one registry across all deployments: /v2/metrics is the whole
         # exchange's view, labelled per model/class/outcome
         self.metrics = metrics if metrics is not None else MetricsRegistry()
+        # the process's garbage-collection pauses (tracing counts them
+        # while a service is live): a monotonic read per generation
+        self.metrics.describe(
+            "max_gc_pause_seconds_total",
+            "Seconds the process stood in garbage collection, by generation")
+        for g in range(3):
+            self.metrics.register_gauge(
+                "max_gc_pause_seconds_total",
+                lambda g=g: round(gc_stats()["pause_s"][g], 6),
+                generation=str(g))
         self._deployments: Dict[str, Deployment] = {}
         self._building: Dict[str, threading.Event] = {}
         self._lock = threading.Lock()

@@ -62,7 +62,8 @@ from repro.serving.metrics import TOKEN_LATENCY_BUCKETS, MetricsRegistry
 from repro.serving.qos import (
     AdmissionController, AdmissionError, QoSConfig, QueueFull,
 )
-from repro.serving.tracing import Tracer, now as _mono
+from repro.serving.tracing import Tracer, now as _mono, phases_ms, span
+from repro.serving.tracing import unwatch_gc, watch_gc
 
 
 class ServiceOverloaded(MAXError):
@@ -233,6 +234,9 @@ class InferenceService(abc.ABC):
         self.metrics.register_gauge(
             "max_active_streams", lambda: self._active_streams,
             model=wrapper.metadata.id)
+        # garbage collections show as max.gc spans while a service is live
+        watch_gc()
+        self._gc_watched = True
 
     @property
     def model_id(self) -> str:
@@ -497,6 +501,9 @@ class InferenceService(abc.ABC):
 
     def close(self):
         self.metrics.unregister_gauges(model=self.model_id)
+        if self._gc_watched:
+            self._gc_watched = False
+            unwatch_gc()
 
 
 # ---------------------------------------------------------------------------
@@ -1302,15 +1309,10 @@ class BatchedService(InferenceService):
         # phase durations from the scheduler's lifecycle stamps — all on
         # the one serving clock, each boundary shared by two phases, so
         # queue_ms + prefill_ms + decode_ms == retire - submit exactly
-        sub = req.submitted_at_s or work.t0
-        adm, ft = req.admitted_at_s, req.first_token_s
-        usage["queue_ms"] = round(
-            max(0.0, (adm if adm is not None else end) - sub) * 1e3, 3)
-        usage["prefill_ms"] = round(
-            max(0.0, (ft if ft is not None else end) - adm) * 1e3, 3) \
-            if adm is not None else 0.0
-        usage["decode_ms"] = round(max(0.0, end - ft) * 1e3, 3) \
-            if ft is not None else 0.0
+        phases = phases_ms(req.submitted_at_s or work.t0, req.admitted_at_s,
+                           req.first_token_s, end)
+        for key in ("queue_ms", "prefill_ms", "decode_ms"):
+            usage[key] = phases[key]
         usage["sched_ticks"] = (req.finished_at_tick
                                 - req.admitted_at_tick + 1) \
             if req.admitted_at_tick >= 0 and req.finished_at_tick >= 0 \
@@ -1571,15 +1573,21 @@ class BatchedService(InferenceService):
             self._thread.start()
 
     def _worker(self):
+        """The serving thread. Its time is tiled by profiler spans:
+        ``max.worker.wait`` (waiting for work, then the coalescing window),
+        ``max.worker.between`` (retries, reaping, pressure and rebuild
+        checks around each tick) and, nested in it, the scheduler's
+        ``max.sched.*`` ticks."""
         while True:
-            with self._cv:
+            with span("max.worker.wait"), self._cv:
                 while (not self.scheduler.has_work() and not self._closed
                        and not (self._retry_q
                                 and self._retry_q[0][0] <= _mono())):
                     self._cv.wait(timeout=self._retry_wait_locked())
                 if self._closed:
                     break
-                failed = self._drain_due_retries_locked()
+                with span("max.worker.between"):
+                    failed = self._drain_due_retries_locked()
                 # coalescing window: give simultaneous arrivals a chance to
                 # share the first prefill/decode batch
                 deadline = _mono() + self.batch_window_s
@@ -1591,8 +1599,9 @@ class BatchedService(InferenceService):
                     self._cv.wait(timeout=remaining)
                 if self._closed:
                     break
-            for work in failed:
-                self._finalize(work)
+            with span("max.worker.between"):
+                for work in failed:
+                    self._finalize(work)
             try:
                 self._run_batch()
             except WorkerKill as e:
@@ -1612,20 +1621,25 @@ class BatchedService(InferenceService):
         batching); the controller decides who gets the next free slot."""
         sched = self.scheduler
         while not self._closed:
-            with self._cv:
-                failed = self._drain_due_retries_locked()
-            for work in failed:
-                self._finalize(work)
-            if not sched.has_work():
-                break
-            self._tick_started = _mono()      # the watchdog's stall clock
-            sched.tick()
-            self._tick_started = None
-            self._stall_flagged = False
+            # one span per iteration with the tick nested in it, so the
+            # calls between the scheduler's spans (where the thread may
+            # give up the GIL) are named too
+            with span("max.worker.between"):
+                with self._cv:
+                    failed = self._drain_due_retries_locked()
+                for work in failed:
+                    self._finalize(work)
+                if not sched.has_work():
+                    break
+                self._tick_started = _mono()  # the watchdog's stall clock
+                sched.tick()
+                self._tick_started = None
+                self._stall_flagged = False
+                self._reap()
+                self._observe_pressure()
+                self._maybe_rebuild()
+        with span("max.worker.between"):
             self._reap()
-            self._observe_pressure()
-            self._maybe_rebuild()
-        self._reap()
 
     # -- fleet hooks (replica groups) --------------------------------------
 
@@ -1725,6 +1739,17 @@ class BatchedService(InferenceService):
             "batch_window_s": self.batch_window_s,
             "queue_depth": self.scheduler.queued_count(),
             "engine_max_batch": self.engine.max_batch,
+            # where the worker's tick time goes: host work vs the one
+            # sync (wall_s == host_s + sync_wait_s), the worker's CPU time
+            # over the host part, and the engine's admission host time
+            "scheduler": {
+                "ticks": ss.ticks, "wall_s": round(ss.wall_s, 6),
+                "host_s": round(ss.host_s, 6),
+                "sync_wait_s": round(ss.sync_wait_s, 6),
+                "host_cpu_s": round(ss.host_cpu_s, 6),
+                "kv_tokens_sum": ss.kv_tokens_sum,
+                "inserts": self.engine.inserts,
+                "insert_host_s": round(self.engine.insert_host_s, 6)},
         })
         if getattr(self.engine, "prefix_cache", None) is not None:
             # also nested under kv_cache; surfaced top-level so dashboards

@@ -170,6 +170,7 @@ def paged_decode_attention(q, k_pool, v_pool, block_table, lengths, *,
     grid = (B, KV, nb)
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel, bs=P),
+        name="paged_decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=grid,
@@ -214,6 +215,7 @@ def decode_attention(q, k, v, lengths, *, bs=256, interpret=False):
     grid = (B, KV, S // bs)
     out = pl.pallas_call(
         functools.partial(_decode_kernel, bs=bs),
+        name="decode_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=grid,

@@ -101,6 +101,7 @@ def flash_attention(q, k, v, *, causal=True, window=None, q_offset=0,
 
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, hd), lambda bh, qi, ki: (bh, qi, 0)),

@@ -44,6 +44,7 @@ def gmm(x, w, *, bc=128, bf=128, bd=256, interpret=False):
     grid = (E, C // bc, f // bf, d // bd)
     out = pl.pallas_call(
         _gmm_kernel,
+        name="gmm",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bc, bd), lambda e, ci, fi, di: (e, ci, di)),

@@ -61,6 +61,7 @@ def rglru_scan(a, b, h0=None, *, bt=128, bw=512, interpret=False):
     grid = (B, W // bw, S // bt)
     h, hlast = pl.pallas_call(
         functools.partial(_rglru_kernel, bt=bt),
+        name="rglru_scan",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bt, bw), lambda bi, wi, ti: (bi, ti, wi)),

@@ -74,6 +74,7 @@ def wkv_scan(r, k, v, w, u, s0=None, *, bt=128, interpret=False):
     grid = (B * H, T // bt)
     y, s_out = pl.pallas_call(
         functools.partial(_wkv_kernel, bt=bt),
+        name="rwkv6_wkv",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bt, N), lambda bh, ti: (bh, ti, 0)),

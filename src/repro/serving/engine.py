@@ -67,9 +67,18 @@ import numpy as np
 from repro.models.model import Model
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.sampling import mask_padded_vocab
-from repro.serving.tracing import now as _now
+from repro.serving.tracing import now as _now, span as _span
 
 F32 = jnp.float32
+
+
+def _named(name: str, fn, *args):
+    """``partial(fn, *args)`` under a stable name: a bare partial compiles
+    as ``jit__unknown``, which a profiler trace cannot tell apart from
+    any other, and this one compiles as ``jit_<name>``."""
+    p = partial(fn, *args)
+    p.__name__ = name
+    return p
 
 
 def _bucket(n: int, minimum: int = 16) -> int:
@@ -121,6 +130,10 @@ class GenerationEngine:
         # prefix-cache hit tokens, pages allocated, COW) — read by the
         # scheduler's tracer immediately after insert_request
         self.last_admission: Optional[Dict[str, Any]] = None
+        # admissions, and their host wall time (the prefill_prep and
+        # prefill_dispatch spans): serial with every first token
+        self.inserts = 0
+        self.insert_host_s = 0.0
 
         # Ring-cache families (sliding-window / hybrid local attention / SSM
         # state) left-pad prompts and wrap or accumulate their caches —
@@ -645,8 +658,9 @@ class GenerationEngine:
         """Dispatch the fused tail fill; returns the first-token scalar."""
         k = _bucket(len(tail), minimum=1)
         if k not in self._fill_jit:
-            self._fill_jit[k] = jax.jit(partial(self._fill_impl, k),
-                                        donate_argnums=(1,))
+            self._fill_jit[k] = jax.jit(
+                _named("prefix_fill", self._fill_impl, k),
+                donate_argnums=(1,))
         padded = np.zeros((k,), np.int32)
         padded[:len(tail)] = tail
         self._cache, self._next_tok, first = self._fill_jit[k](
@@ -823,73 +837,96 @@ class GenerationEngine:
         """Prefill ``prompt`` into ``slot``; returns the first generated
         token as an *unforced* device scalar (greedy argmax over the prefill
         logits, computed on device). Callers defer the host read to their
-        next sync point — admission never stalls the decode loop."""
+        next sync point — admission never stalls the decode loop.
+
+        Two spans cover it: ``max.engine.prefill_prep`` (padding, pages,
+        the prompt's copy to the device) and ``max.engine.prefill_dispatch``
+        (the enqueue of the prefill, insert and first-token programs);
+        ``inserts`` / ``insert_host_s`` count them."""
         assert not self._active[slot], f"slot {slot} busy"
-        bucket = _bucket(len(prompt))
-        if bucket > self.max_seq:
-            raise ValueError(f"prompt {len(prompt)} exceeds max_seq {self.max_seq}")
+        if _bucket(len(prompt)) > self.max_seq:
+            raise ValueError(
+                f"prompt {len(prompt)} exceeds max_seq {self.max_seq}")
+        t0 = _now()
         # prefix-cached admission applies only to requests whose KV is a
         # pure function of the token ids: anything carrying extra inputs
         # (image embeds, audio frames) takes the plain paged path and its
         # pages are never registered
         if (self.prefix_cache is not None and not extra
                 and not self.extra_inputs):
-            return self._insert_cached(list(prompt), slot)
-        if self.prefix_cache is not None:
-            self._slot_cacheable[slot] = False
-        if bucket not in self._prefill_jit:
-            self._prefill_jit[bucket] = jax.jit(self._prefill_impl)
-        # Ring-cache families (sliding-window / hybrid local attention) need
-        # contiguous positions, so their prompts are LEFT-padded and pads are
-        # treated as context. Linear caches RIGHT-pad; causal masking keeps
-        # pads out of real-token attention and decode masks by true length.
-        # (SSM states are cumulative too, so stateful families all left-pad.)
-        ring = self._ring
-        padded = np.zeros((1, bucket), np.int32)
-        if ring:
-            padded[0, bucket - len(prompt):] = prompt
-            true_len = bucket
+            first = self._insert_cached(list(prompt), slot)
         else:
-            padded[0, :len(prompt)] = prompt
-            true_len = len(prompt)
-        batch = {"tokens": jnp.asarray(padded),
-                 "prompt_lengths": jnp.asarray([true_len], np.int32)}
-        for k, v in (extra or self.extra_inputs).items():
-            batch[k] = v
-        if self.paged:
-            # allocate the prefill's pages — plus the page the FIRST decode
-            # write lands in, so a fresh admission can never be starved by
-            # co-tenants before its first chunk — BEFORE dispatching
-            # compute; the scheduler gates admission on can_admit so this
-            # only trips for direct callers outrunning the pool.
-            # blocks_for_prompt is the ONE statement of this reservation
-            # rule: the admission gate and the allocator must never diverge
-            need = self.blocks_for_prompt(len(prompt))
-            if not self._alloc_blocks(slot, need):
-                raise RuntimeError(
-                    f"KV pool exhausted: prompt needs {need} pages, "
-                    f"{len(self._free_pool)} of {self.kv_pool_blocks} free")
-        # host mirrors flip BEFORE the (possibly compiling) prefill
-        # dispatch: stats readers on other threads must never observe
-        # allocated pages without an owner
-        self._lengths[slot] = true_len
-        self._prompt_lens[slot] = len(prompt)
-        self._prefill_lens[slot] = true_len
-        self._active[slot] = True
-        try:
-            logits, one_cache = self._prefill_jit[bucket](self.params, batch)
-            if self.paged:
-                self._cache = self._insert(
-                    self._cache, one_cache, jnp.asarray(self._table[slot]),
-                    jnp.asarray(slot, jnp.int32))
+            first = self._insert_plain(prompt, slot, extra)
+        self.inserts += 1
+        self.insert_host_s += _now() - t0
+        return first
+
+    def _insert_plain(self, prompt: List[int], slot: int,
+                      extra: Optional[Dict[str, Any]]) -> jax.Array:
+        """Bucketed B=1 prefill, then the insert into the batch cache."""
+        with _span("max.engine.prefill_prep"):
+            bucket = _bucket(len(prompt))
+            if self.prefix_cache is not None:
+                self._slot_cacheable[slot] = False
+            if bucket not in self._prefill_jit:
+                self._prefill_jit[bucket] = jax.jit(self._prefill_impl)
+            # Ring-cache families (sliding-window / hybrid local attention)
+            # need contiguous positions, so their prompts are LEFT-padded
+            # and pads are treated as context. Linear caches RIGHT-pad;
+            # causal masking keeps pads out of real-token attention and
+            # decode masks by true length. (SSM states are cumulative too,
+            # so stateful families all left-pad.)
+            ring = self._ring
+            padded = np.zeros((1, bucket), np.int32)
+            if ring:
+                padded[0, bucket - len(prompt):] = prompt
+                true_len = bucket
             else:
-                self._cache = self._insert(self._cache, one_cache,
-                                           jnp.asarray(slot, jnp.int32))
-            first, self._next_tok = self._first_tok(
-                logits, self._next_tok, jnp.asarray(slot, jnp.int32))
-        except Exception:
-            self.release_slot(slot)   # no orphaned slot or leaked pages
-            raise
+                padded[0, :len(prompt)] = prompt
+                true_len = len(prompt)
+            batch = {"tokens": jnp.asarray(padded),
+                     "prompt_lengths": jnp.asarray([true_len], np.int32)}
+            for k, v in (extra or self.extra_inputs).items():
+                batch[k] = v
+            if self.paged:
+                # allocate the prefill's pages — plus the page the FIRST
+                # decode write lands in, so a fresh admission can never be
+                # starved by co-tenants before its first chunk — BEFORE
+                # dispatching compute; the scheduler gates admission on
+                # can_admit so this only trips for direct callers
+                # outrunning the pool. blocks_for_prompt is the ONE
+                # statement of this reservation rule: the admission gate
+                # and the allocator must never diverge
+                need = self.blocks_for_prompt(len(prompt))
+                if not self._alloc_blocks(slot, need):
+                    raise RuntimeError(
+                        f"KV pool exhausted: prompt needs {need} pages, "
+                        f"{len(self._free_pool)} of {self.kv_pool_blocks} "
+                        "free")
+            # host mirrors flip BEFORE the (possibly compiling) prefill
+            # dispatch: stats readers on other threads must never observe
+            # allocated pages without an owner
+            self._lengths[slot] = true_len
+            self._prompt_lens[slot] = len(prompt)
+            self._prefill_lens[slot] = true_len
+            self._active[slot] = True
+        with _span("max.engine.prefill_dispatch"):
+            try:
+                logits, one_cache = self._prefill_jit[bucket](self.params,
+                                                              batch)
+                if self.paged:
+                    self._cache = self._insert(
+                        self._cache, one_cache,
+                        jnp.asarray(self._table[slot]),
+                        jnp.asarray(slot, jnp.int32))
+                else:
+                    self._cache = self._insert(self._cache, one_cache,
+                                               jnp.asarray(slot, jnp.int32))
+                first, self._next_tok = self._first_tok(
+                    logits, self._next_tok, jnp.asarray(slot, jnp.int32))
+            except Exception:
+                self.release_slot(slot)   # no orphaned slot or leaked pages
+                raise
         # host-side admission summary for observability (the scheduler's
         # tracer reads it right after insert — never a device value)
         self.last_admission = {
@@ -918,62 +955,66 @@ class GenerationEngine:
         Freshly computed full prompt pages register immediately, so
         co-batched duplicates admitted later the same tick already hit.
         """
-        n = len(prompt)
-        P = self.page_size
-        cache = self.prefix_cache
-        total = -(-(n + 1) // P)          # prompt pages + first decode write
-        hits = cache.match(prompt)
-        hit_len = len(hits) * P
-        assert not self._slot_blocks[slot], f"slot {slot} holds pages"
-        for i, pg in enumerate(hits):
-            self._slot_blocks[slot].append(pg)
-            self._table[slot, i] = pg
-            self._page_refs[pg] += 1
-            cache.ref_page(pg)
-        if not self._alloc_blocks(slot, total - len(hits)):
-            self.release_slot(slot)       # drop the shared refs taken above
-            raise RuntimeError(
-                f"KV pool exhausted: prompt needs {total - len(hits)} new "
-                f"pages, {self.available_blocks()} of "
-                f"{self.kv_pool_blocks} claimable")
-        self._push_table_row(slot)
-        # host mirrors flip BEFORE the dispatches, same rule as the plain
-        # path (paged prompts are linear: logical == physical == n)
-        self._lengths[slot] = n
-        self._prompt_lens[slot] = n
-        self._prefill_lens[slot] = n
-        self._active[slot] = True
-        self._slot_cacheable[slot] = True
-        try:
-            if hit_len >= n:              # full hit: replay the last token
-                start = n - 1
-                if not self._make_writable(slot, start):
-                    raise RuntimeError(
-                        "KV pool exhausted: no page for the replay "
-                        "copy-on-write")
-            elif not hits and n - 1 >= P:
-                # cold miss: aligned prefix through the regular prefill
-                start = ((n - 1) // P) * P
-                pb = _bucket(start)
-                if pb not in self._prefill_jit:
-                    self._prefill_jit[pb] = jax.jit(self._prefill_impl)
-                padded = np.zeros((1, pb), np.int32)
-                padded[0, :start] = prompt[:start]
-                batch = {"tokens": jnp.asarray(padded),
-                         "prompt_lengths": jnp.asarray([start], np.int32)}
-                _, one_cache = self._prefill_jit[pb](self.params, batch)
-                self._cache = self._insert(
-                    self._cache, one_cache, jnp.asarray(self._table[slot]),
-                    jnp.asarray(slot, jnp.int32))
-            else:                         # partial hit (or tiny prompt)
-                start = hit_len
-            first = self._fill(prompt[start:], start, slot)
-            keys = cache.chain_keys(prompt)
-            for i in range(len(hits), n // P):
-                cache.register(keys[i], self._slot_blocks[slot][i])
-        except Exception:
-            self.release_slot(slot)   # no orphaned slot or leaked pages
-            raise
+        with _span("max.engine.prefill_prep"):
+            n = len(prompt)
+            P = self.page_size
+            cache = self.prefix_cache
+            total = -(-(n + 1) // P)      # prompt pages + first decode write
+            hits = cache.match(prompt)
+            hit_len = len(hits) * P
+            assert not self._slot_blocks[slot], f"slot {slot} holds pages"
+            for i, pg in enumerate(hits):
+                self._slot_blocks[slot].append(pg)
+                self._table[slot, i] = pg
+                self._page_refs[pg] += 1
+                cache.ref_page(pg)
+            if not self._alloc_blocks(slot, total - len(hits)):
+                self.release_slot(slot)   # drop the shared refs taken above
+                raise RuntimeError(
+                    f"KV pool exhausted: prompt needs {total - len(hits)} "
+                    f"new pages, {self.available_blocks()} of "
+                    f"{self.kv_pool_blocks} claimable")
+            self._push_table_row(slot)
+            # host mirrors flip BEFORE the dispatches, same rule as the
+            # plain path (paged prompts are linear: logical == physical)
+            self._lengths[slot] = n
+            self._prompt_lens[slot] = n
+            self._prefill_lens[slot] = n
+            self._active[slot] = True
+            self._slot_cacheable[slot] = True
+        with _span("max.engine.prefill_dispatch"):
+            try:
+                if hit_len >= n:          # full hit: replay the last token
+                    start = n - 1
+                    if not self._make_writable(slot, start):
+                        raise RuntimeError(
+                            "KV pool exhausted: no page for the replay "
+                            "copy-on-write")
+                elif not hits and n - 1 >= P:
+                    # cold miss: aligned prefix through the regular prefill
+                    start = ((n - 1) // P) * P
+                    pb = _bucket(start)
+                    if pb not in self._prefill_jit:
+                        self._prefill_jit[pb] = jax.jit(self._prefill_impl)
+                    padded = np.zeros((1, pb), np.int32)
+                    padded[0, :start] = prompt[:start]
+                    batch = {"tokens": jnp.asarray(padded),
+                             "prompt_lengths": jnp.asarray([start],
+                                                           np.int32)}
+                    _, one_cache = self._prefill_jit[pb](self.params, batch)
+                    self._cache = self._insert(
+                        self._cache, one_cache,
+                        jnp.asarray(self._table[slot]),
+                        jnp.asarray(slot, jnp.int32))
+                else:                     # partial hit (or tiny prompt)
+                    start = hit_len
+                first = self._fill(prompt[start:], start, slot)
+                keys = cache.chain_keys(prompt)
+                for i in range(len(hits), n // P):
+                    cache.register(keys[i], self._slot_blocks[slot][i])
+            except Exception:
+                self.release_slot(slot)   # no orphaned slot or leaked pages
+                raise
         # warm-vs-cold is distinguishable here: hit tokens were installed
         # by reference, only the remainder paid pages/compute
         self.last_admission = {
@@ -1115,7 +1156,8 @@ class GenerationEngine:
         # it); decode_chunk is only the default
         k = self.decode_chunk if k is None else max(1, int(k))
         if k not in self._chunk_jit:
-            self._chunk_jit[k] = jax.jit(partial(self._chunk_impl, k))
+            self._chunk_jit[k] = jax.jit(
+                _named("decode_chunk", self._chunk_impl, k))
         if self.paged:
             # every budgeted write this chunk needs an allocated page
             # BEFORE dispatch (the device cannot allocate); clamping the

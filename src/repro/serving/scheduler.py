@@ -55,7 +55,16 @@ Invariants (property-tested):
   instead of writing past ``max_seq``;
 - throughput accounting: sum of emitted tokens == sum over requests, and
   ``wall_s`` accrues per tick so ``tokens_per_s`` is real whichever loop
-  drives ``tick()``.
+  drives ``tick()``; ``wall_s == host_s + sync_wait_s``.
+
+A tick is four profiler spans in a row (``tracing.span``): ``max.sched.admit``
+(cancel sweep, admission and the prefills it dispatches),
+``max.sched.dispatch`` (budgets, pages, the chunk's enqueue),
+``max.sched.sync`` (the one sanctioned sync, including the wait to take
+the GIL back) and ``max.sched.deliver`` (commit, sinks, retire). The
+stats count the same boundaries: ``sync_wait_s`` is the sync span's wall
+time, ``host_s`` the rest of the tick, ``host_cpu_s`` the worker's CPU
+time over that rest.
 
 Thread-safety: ``submit``/``poll``/``tick`` take an internal lock so HTTP
 threads can enqueue while a single worker thread drives ``tick`` (the model
@@ -89,7 +98,8 @@ import numpy as np
 
 from repro.serving.engine import GenerationEngine
 from repro.serving.faults import FaultPlane, InjectedFault
-from repro.serving.tracing import now as _now
+from repro.serving.tracing import cpu_now as _cpu_now, now as _now
+from repro.serving.tracing import span as _span
 
 
 # eq=False: requests compare by IDENTITY. Beyond being semantically right
@@ -153,8 +163,15 @@ class SchedulerStats:
     rejected: int = 0                 # retired with PROMPT_TOO_LONG
     engine_faults: int = 0            # retired with ENGINE_FAULT
     wall_s: float = 0.0               # accrued per tick (run() adds nothing)
+    host_s: float = 0.0               # tick wall time outside the sync
+    sync_wait_s: float = 0.0          # tick wall time inside the sync
+    host_cpu_s: float = 0.0           # worker CPU time over host_s
     occupancy_sum: int = 0            # sum of active-batch sizes per decode
     max_occupancy: int = 0
+    # sum over decode steps of the batch's live context tokens (host
+    # length mirror): over decode_steps * max_batch * max_seq, the share
+    # of the reserved KV in use
+    kv_tokens_sum: int = 0
 
     @property
     def tokens_per_s(self) -> float:
@@ -692,142 +709,186 @@ class ContinuousBatchingScheduler:
         Exactly one host sync per tick (reading the chunk's token block),
         however many tokens the chunk produced."""
         t0 = _now()
+        c0 = _cpu_now()
         emitted_before = self.stats.emitted_tokens
         faults_before = self.stats.engine_faults
+        prefills_before = self.stats.prefills
         chunk_k = 0
-        with self._lock:
-            self._sweep_cancelled()
-            self._admit()
-            toks = emitted = None
-            if self.active:
-                budgets = np.zeros((self.engine.max_batch,), np.int32)
-                pending = {id(r) for r, _ in self._pending_first}
-                for slot, req in self.active.items():
-                    have = len(req.output) + (1 if id(req) in pending else 0)
-                    budgets[slot] = max(0, req.max_new_tokens - have)
-                if self.engine.paged:
-                    # every KV write this chunk needs a pool page secured
-                    # BEFORE dispatch. A slot that cannot take one more
-                    # write retires NOW (its pages may unblock the slots
-                    # ensured after it); a partially-secured slot decodes
-                    # up to its headroom and retries next tick.
+        with _span("max.sched.tick") as tick_span, self._lock:
+            with _span("max.sched.admit") as sp:
+                self._sweep_cancelled()
+                self._admit()
+                sp.set_metadata(
+                    admitted=self.stats.prefills - prefills_before)
+                t_admit = _now()
+            with _span("max.sched.dispatch") as sp:
+                toks = emitted = None
+                if self.active:
+                    budgets = np.zeros((self.engine.max_batch,), np.int32)
+                    pending = {id(r) for r, _ in self._pending_first}
+                    for slot, req in self.active.items():
+                        have = len(req.output) + (1 if id(req) in pending
+                                                  else 0)
+                        budgets[slot] = max(0, req.max_new_tokens - have)
+                    if self.engine.paged:
+                        # every KV write this chunk needs a pool page
+                        # secured BEFORE dispatch. A slot that cannot take
+                        # one more write retires NOW (its pages may unblock
+                        # the slots ensured after it); a partially-secured
+                        # slot decodes up to its headroom and retries next
+                        # tick.
+                        for slot, req in list(self.active.items()):
+                            if budgets[slot] <= 0:
+                                continue
+                            got = self.engine.ensure_capacity(
+                                slot,
+                                min(self.decode_chunk, int(budgets[slot])))
+                            if got == 0:
+                                if (self.engine.context_len(slot)
+                                        >= self.engine.max_seq):
+                                    self._overflow(req)
+                                else:
+                                    self._pool_exhausted(req)
+                                budgets[slot] = 0
+                                continue
+                            budgets[slot] = min(int(budgets[slot]), got)
+                if self.active:
+                    # budget-aligned chunk: never decode past the earliest
+                    # completion, so a finishing request's result is
+                    # visible at the very next sync instead of idling
+                    # masked behind longer co-tenants (interactive latency
+                    # == stepwise while long batches still amortize the
+                    # full chunk). Rounded down to a power of two so the
+                    # engine compiles a bounded set of scan programs
+                    # ({1,2,4,8,...}) — a solo request's budget decomposes
+                    # binarily, warming every size it will ever use.
+                    k = min(self.decode_chunk,
+                            max(1, min(int(budgets[s]) for s in self.active)))
+                    k = 1 << (k.bit_length() - 1)
+                    chunk_k = k
+                    try:
+                        if self.faults is not None:
+                            # may raise InjectedFault / WorkerKill, or
+                            # stall. WorkerKill is a BaseException: it
+                            # unwinds past tick (the `with` releases the
+                            # lock) and kills the driving thread — the
+                            # watchdog's problem.
+                            self.faults.check_chunk(self.stats.ticks,
+                                                    sorted(self.active))
+                        # maxlint: allow[lock-discipline] reason=single-owner design: the scheduler RLock is the engine ownership token and submit() is lock-free, so no request thread ever queues behind dispatch
+                        self._rng, sub = jax.random.split(self._rng)
+                        # maxlint: allow[lock-discipline] reason=single-owner design: the scheduler RLock is the engine ownership token and submit() is lock-free, so no request thread ever queues behind dispatch
+                        toks, emitted = self.engine.step_chunk(
+                            sub, self._temps, budgets, k)
+                    except InjectedFault as e:
+                        # scoped fault: quarantine only the named victim;
+                        # the co-batch skips this chunk (nothing was
+                        # committed) and resumes next tick
+                        if e.slot is not None and e.slot in self.active:
+                            self._quarantine_slot(e.slot, str(e), e.site)
+                        else:
+                            self.quarantine_active(str(e), site=e.site)
+                        toks = emitted = None
+                        chunk_k = 0
+                    except Exception as e:
+                        # real dispatch fault: the whole co-batch's device
+                        # state is suspect — quarantine everything, keep
+                        # the worker alive
+                        self.quarantine_active(
+                            f"chunk dispatch failed: {e}", site="chunk")
+                        toks = emitted = None
+                        chunk_k = 0
+                sp.set_metadata(k=chunk_k)
+            with _span("max.sched.sync"):
+                c_sync = _cpu_now()
+                t_sync = _now()
+                # single sync point: first tokens of fresh admissions,
+                # then the chunk block (np.asarray forces both)
+                self._resolve_pending_first()
+                if toks is not None:
+                    try:
+                        # maxlint: allow[host-sync] reason=THE one sanctioned chunk-boundary sync: a single blocking transfer drains the whole chunk
+                        toks = np.asarray(toks)       # the tick's host sync
+                        # maxlint: allow[host-sync] reason=THE one sanctioned chunk-boundary sync: a single blocking transfer drains the whole chunk
+                        emitted = np.asarray(emitted)
+                    except Exception as e:
+                        # the sync surfaces deferred device failures:
+                        # nothing was committed, no token reached any sink
+                        # — the whole batch retires ENGINE_FAULT and
+                        # remains retry-safe
+                        self.quarantine_active(
+                            f"chunk sync failed: {e}", site="chunk")
+                        toks = None
+                t_deliver = _now()
+                c_deliver = _cpu_now()
+            with _span("max.sched.deliver"):
+                steps_before = self.stats.decode_steps
+                kv_before = self.stats.kv_tokens_sum
+                if toks is not None:
+                    counts = emitted.sum(axis=1).astype(np.int32)
+                    self.engine.commit_chunk(counts)
+                    per_step = emitted.sum(axis=0)
+                    self.stats.chunks += 1
+                    self.stats.decode_steps += int((per_step > 0).sum())
+                    self.stats.occupancy_sum += int(per_step.sum())
+                    self.stats.max_occupancy = max(
+                        self.stats.max_occupancy,
+                        int(per_step.max(initial=0)))
                     for slot, req in list(self.active.items()):
-                        if budgets[slot] <= 0:
-                            continue
-                        got = self.engine.ensure_capacity(
-                            slot, min(self.decode_chunk, int(budgets[slot])))
-                        if got == 0:
-                            if (self.engine.context_len(slot)
-                                    >= self.engine.max_seq):
-                                self._overflow(req)
-                            else:
-                                self._pool_exhausted(req)
-                            budgets[slot] = 0
-                            continue
-                        budgets[slot] = min(int(budgets[slot]), got)
-            if self.active:
-                # budget-aligned chunk: never decode past the earliest
-                # completion, so a finishing request's result is visible at
-                # the very next sync instead of idling masked behind
-                # longer co-tenants (interactive latency == stepwise while
-                # long batches still amortize the full chunk). Rounded down
-                # to a power of two so the engine compiles a bounded set of
-                # scan programs ({1,2,4,8,...}) — a solo request's budget
-                # decomposes binarily, warming every size it will ever use.
-                k = min(self.decode_chunk,
-                        max(1, min(int(budgets[s]) for s in self.active)))
-                k = 1 << (k.bit_length() - 1)
-                chunk_k = k
-                try:
-                    if self.faults is not None:
-                        # may raise InjectedFault / WorkerKill, or stall.
-                        # WorkerKill is a BaseException: it unwinds past
-                        # tick (the `with` releases the lock) and kills
-                        # the driving thread — the watchdog's problem.
-                        self.faults.check_chunk(self.stats.ticks,
-                                                sorted(self.active))
-                    # maxlint: allow[lock-discipline] reason=single-owner design: the scheduler RLock is the engine ownership token and submit() is lock-free, so no request thread ever queues behind dispatch
-                    self._rng, sub = jax.random.split(self._rng)
-                    # maxlint: allow[lock-discipline] reason=single-owner design: the scheduler RLock is the engine ownership token and submit() is lock-free, so no request thread ever queues behind dispatch
-                    toks, emitted = self.engine.step_chunk(
-                        sub, self._temps, budgets, k)
-                except InjectedFault as e:
-                    # scoped fault: quarantine only the named victim; the
-                    # co-batch skips this chunk (nothing was committed)
-                    # and resumes next tick
-                    if e.slot is not None and e.slot in self.active:
-                        self._quarantine_slot(e.slot, str(e), e.site)
-                    else:
-                        self.quarantine_active(str(e), site=e.site)
-                    toks = emitted = None
-                    chunk_k = 0
-                except Exception as e:
-                    # real dispatch fault: the whole co-batch's device
-                    # state is suspect — quarantine everything, keep the
-                    # worker alive
-                    self.quarantine_active(
-                        f"chunk dispatch failed: {e}", site="chunk")
-                    toks = emitted = None
-                    chunk_k = 0
-            # single sync point: first tokens of fresh admissions, then the
-            # chunk block (np.asarray forces both)
-            self._resolve_pending_first()
-            if toks is not None:
-                try:
-                    # maxlint: allow[host-sync] reason=THE one sanctioned chunk-boundary sync: a single blocking transfer drains the whole chunk
-                    toks = np.asarray(toks)       # the tick's host sync
-                    # maxlint: allow[host-sync] reason=THE one sanctioned chunk-boundary sync: a single blocking transfer drains the whole chunk
-                    emitted = np.asarray(emitted)
-                except Exception as e:
-                    # the sync surfaces deferred device failures: nothing
-                    # was committed, no token reached any sink — the whole
-                    # batch retires ENGINE_FAULT and remains retry-safe
-                    self.quarantine_active(
-                        f"chunk sync failed: {e}", site="chunk")
-                    toks = None
-            if toks is not None:
-                counts = emitted.sum(axis=1).astype(np.int32)
-                self.engine.commit_chunk(counts)
-                per_step = emitted.sum(axis=0)
-                self.stats.chunks += 1
-                self.stats.decode_steps += int((per_step > 0).sum())
-                self.stats.occupancy_sum += int(per_step.sum())
-                self.stats.max_occupancy = max(self.stats.max_occupancy,
-                                               int(per_step.max(initial=0)))
-                for slot, req in list(self.active.items()):
-                    n = int(counts[slot])
-                    if n:
-                        chunk_toks = [int(t) for t in toks[slot, :n]]
-                        req.output.extend(chunk_toks)
-                        self.stats.emitted_tokens += n
-                        self._feed_sink(req, chunk_toks)
-                        if req.trace is not None:
-                            req.trace.event("chunk", n=n, k=chunk_k,
-                                            occupancy=len(self.active))
-                    self._maybe_finish(req)
-                    # physical capacity only: a pool-starved (but not
-                    # max_seq-full) slot is retired by the pre-chunk ensure
-                    # pass with KV_POOL_EXHAUSTED, not mislabelled here
-                    if not req.done and (self.engine.context_len(slot)
-                                         >= self.engine.max_seq):
-                        self._overflow(req)
-                if self.stats.engine_faults == faults_before:
-                    self.fault_streak = 0         # a clean committed chunk
-            if self.tracer is not None:
-                # tick lane + occupancy counter tracks, host mirrors only
-                # (blocks_in_use / prefix stats never touch the device)
-                kv = self.engine.blocks_in_use() if self.engine.paged \
-                    else None
-                pages = None
-                if getattr(self.engine, "prefix_cache", None) is not None:
-                    pages = self.engine.prefix_stats().get("cached_pages")
-                self.tracer.tick(
-                    self.stats.ticks, t0, _now(), k=chunk_k,
-                    active=len(self.active),
-                    emitted=self.stats.emitted_tokens - emitted_before,
-                    kv_blocks_in_use=kv, prefix_cached_pages=pages)
-            self.stats.ticks += 1
-            self.stats.wall_s += _now() - t0
+                        n = int(counts[slot])
+                        if n:
+                            # the slot's n steps saw L-n+1 ... L tokens
+                            # in its cache (L: the length mirror after
+                            # the commit)
+                            length = self.engine.context_len(slot)
+                            self.stats.kv_tokens_sum += \
+                                n * length - n * (n - 1) // 2
+                            chunk_toks = [int(t) for t in toks[slot, :n]]
+                            req.output.extend(chunk_toks)
+                            self.stats.emitted_tokens += n
+                            self._feed_sink(req, chunk_toks)
+                            if req.trace is not None:
+                                req.trace.event("chunk", n=n, k=chunk_k,
+                                                occupancy=len(self.active))
+                        self._maybe_finish(req)
+                        # physical capacity only: a pool-starved (but not
+                        # max_seq-full) slot is retired by the pre-chunk
+                        # ensure pass with KV_POOL_EXHAUSTED, not
+                        # mislabelled here
+                        if not req.done and (self.engine.context_len(slot)
+                                             >= self.engine.max_seq):
+                            self._overflow(req)
+                    if self.stats.engine_faults == faults_before:
+                        self.fault_streak = 0     # a clean committed chunk
+                if self.tracer is not None:
+                    # tick lane + occupancy counter tracks, host mirrors
+                    # only (blocks_in_use / prefix stats never touch the
+                    # device)
+                    kv = self.engine.blocks_in_use() if self.engine.paged \
+                        else None
+                    pages = None
+                    if getattr(self.engine, "prefix_cache", None) is not None:
+                        pages = self.engine.prefix_stats().get("cached_pages")
+                    self.tracer.tick(
+                        self.stats.ticks, t0, _now(), k=chunk_k,
+                        active=len(self.active),
+                        emitted=self.stats.emitted_tokens - emitted_before,
+                        kv_blocks_in_use=kv, prefix_cached_pages=pages,
+                        parts=(t_admit, t_sync, t_deliver))
+                self.stats.ticks += 1
+                c1 = _cpu_now()
+                t1 = _now()
+                wall, sync = t1 - t0, t_deliver - t_sync
+                host_cpu = (c1 - c0) - (c_deliver - c_sync)
+                self.stats.wall_s += wall
+                self.stats.sync_wait_s += sync
+                self.stats.host_s += wall - sync
+                self.stats.host_cpu_s += host_cpu
+                # the tick's share of the counters, for a profiler trace
+                tick_span.set_metadata(
+                    host_s=wall - sync, sync_s=sync, cpu_s=host_cpu,
+                    steps=self.stats.decode_steps - steps_before,
+                    kv_tokens=self.stats.kv_tokens_sum - kv_before)
 
     def run(self, *, max_ticks: int = 10_000) -> SchedulerStats:
         """Run until queue + active drain (or tick budget). ``wall_s`` is

@@ -31,18 +31,32 @@ Design constraints (mirroring ``metrics.py``):
   stamps, span boundaries, and histogram observations all read it, so
   every differenced pair of timestamps is meaningful (``time.monotonic``
   and ``time.perf_counter`` have unrelated epochs — mixing them was a
-  live bug class this module retires).
+  live bug class this module retires). :func:`cpu_now` (the calling
+  thread's CPU time) is read beside it where a boundary needs both, and
+  :func:`clock_pair` ties it to the profiler's clock.
+
+Inside the serving loop the same boundaries also open profiler spans
+(:func:`span`, names under ``max.``): the scheduler tick and its parts,
+the engine's admission, the worker between ticks, and every garbage
+collection (:func:`watch_gc`). They land in the JAX profiler's host
+plane beside the device ops, on the profiler's clock, so a trace says
+what the host was doing in each gap of the device; with no profiler
+session each costs one object.
 """
 
 from __future__ import annotations
 
+import gc
 import itertools
 import threading
 import time
 from collections import OrderedDict, deque
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
-__all__ = ("now", "RequestTrace", "Tracer")
+from jax.profiler import TraceAnnotation
+
+__all__ = ("now", "cpu_now", "clock_pair", "span", "phases_ms",
+           "watch_gc", "unwatch_gc", "gc_stats", "RequestTrace", "Tracer")
 
 
 def now() -> float:
@@ -53,6 +67,121 @@ def now() -> float:
     this one function so any two of them are mutually comparable.
     """
     return time.monotonic()
+
+
+def cpu_now() -> float:
+    """CPU seconds the calling thread has run. Read beside :func:`now`
+    at a boundary, it splits wall time the thread computed from wall time
+    it waited (for the GIL, or for a machine that did not run it)."""
+    return time.thread_time()
+
+
+def clock_pair() -> Dict[str, float]:
+    """The serving clock and the clock the JAX profiler stamps host
+    events with (``CLOCK_REALTIME`` in ns; a trace's event times are
+    offsets from its ``profile_start_time`` on that clock), read
+    together: the pair maps an export onto a profiler trace."""
+    serving = now()
+    return {"serving_clock_s": serving, "profiler_clock_ns": time.time_ns()}
+
+
+def span(name: str, **attrs) -> TraceAnnotation:
+    """A profiler span over one boundary of the serving loop (use as a
+    context manager; ``set_metadata`` adds attributes known only at its
+    end). With no profiler session it records nothing and costs one
+    object, so spans open per tick or per admission, never per token."""
+    return TraceAnnotation(name, **attrs)
+
+
+def phases_ms(submitted: float, admitted: Optional[float],
+              first_token: Optional[float],
+              end: float) -> Dict[str, float]:
+    """Queue, prefill and decode durations in ms from a request's
+    lifecycle stamps. Each boundary is one shared timestamp, so
+    ``queue_ms + prefill_ms + decode_ms == e2e_ms`` exactly (before
+    rounding)."""
+    ms = lambda a, b: round(max(0.0, b - a) * 1e3, 3)  # noqa: E731
+    return {
+        "queue_ms": ms(submitted, admitted if admitted is not None else end),
+        "prefill_ms": (ms(admitted, first_token if first_token is not None
+                          else end) if admitted is not None else 0.0),
+        "decode_ms": ms(first_token, end) if first_token is not None
+        else 0.0,
+        "e2e_ms": ms(submitted, end),
+    }
+
+
+# -- garbage collection: one span and process counters per collection -------
+
+class _GCWatch:
+    """The process's garbage collections, seen from ``gc.callbacks``: a
+    ``max.gc`` span and pause counters per generation. One per process,
+    since the collector is; the hook is installed while any service holds
+    a reference (:func:`watch_gc`)."""
+
+    GENERATIONS = 3
+
+    def __init__(self):
+        self.collections = [0] * self.GENERATIONS
+        self.pause_s = [0.0] * self.GENERATIONS
+        self.max_pause_s = [0.0] * self.GENERATIONS
+        self._open: Optional[Tuple[float, TraceAnnotation]] = None
+        self._users = 0
+        self._users_lock = threading.Lock()
+
+    def on_gc(self, phase: str, info: Dict[str, int]) -> None:
+        """Takes no lock — a collection may start while its thread holds
+        any lock — and needs none: collections never overlap."""
+        g = info["generation"]
+        if phase == "start":
+            sp = TraceAnnotation("max.gc", generation=g)
+            sp.__enter__()
+            self._open = (now(), sp)
+            return
+        if self._open is None:
+            return                # installed while a collection ran
+        (t0, sp), self._open = self._open, None
+        sp.set_metadata(collected=info["collected"])
+        sp.__exit__(None, None, None)
+        pause = now() - t0
+        self.collections[g] += 1
+        self.pause_s[g] += pause
+        self.max_pause_s[g] = max(self.max_pause_s[g], pause)
+
+    def acquire(self) -> None:
+        with self._users_lock:
+            self._users += 1
+            if self._users == 1:
+                gc.callbacks.append(self.on_gc)
+
+    def release(self) -> None:
+        with self._users_lock:
+            self._users = max(0, self._users - 1)
+            if self._users == 0 and self.on_gc in gc.callbacks:
+                gc.callbacks.remove(self.on_gc)
+                self._open = None
+
+
+_GC = _GCWatch()
+
+
+def watch_gc() -> None:
+    """Install the collection hook; one per process however many services
+    call it (each live service holds one reference)."""
+    _GC.acquire()
+
+
+def unwatch_gc() -> None:
+    """Drop one reference; the last one removes the hook."""
+    _GC.release()
+
+
+def gc_stats() -> Dict[str, List[float]]:
+    """Collections, total and longest pause (s) per generation, counted
+    while a service was live."""
+    return {"collections": list(_GC.collections),
+            "pause_s": list(_GC.pause_s),
+            "max_pause_s": list(_GC.max_pause_s)}
 
 
 # events a compacted trace keeps: the lifecycle skeleton an operator needs
@@ -136,16 +265,9 @@ class RequestTrace:
         ``queue_ms + prefill_ms + decode_ms == e2e_ms`` exactly: each
         phase boundary is a single shared timestamp."""
         end = self.finished_at if self.finished_at is not None else now()
-        adm, ft = self.admitted_at, self.first_token_at
-        queue_end = adm if adm is not None else end
-        prefill_end = ft if ft is not None else (end if adm is not None
-                                                 else None)
-        ms = lambda a, b: round(max(0.0, (b - a)) * 1e3, 3)  # noqa: E731
         return {
-            "queue_ms": ms(self.submitted_at, queue_end),
-            "prefill_ms": ms(adm, prefill_end) if adm is not None else 0.0,
-            "decode_ms": ms(ft, end) if ft is not None else 0.0,
-            "e2e_ms": ms(self.submitted_at, end),
+            **phases_ms(self.submitted_at, self.admitted_at,
+                        self.first_token_at, end),
             "sched_ticks": (self.finished_tick - self.admitted_tick + 1
                             if self.admitted_tick >= 0
                             and self.finished_tick >= 0 else 0),
@@ -200,6 +322,11 @@ class RequestTrace:
         capture evicts fast traces to this form under ring pressure)."""
         self.events = [e for e in self.events if e[1] in _LIFECYCLE_EVENTS]
         self.compacted = True
+
+
+# the spans a scheduler tick is made of, in order
+TICK_PARTS = ("max.sched.admit", "max.sched.dispatch", "max.sched.sync",
+              "max.sched.deliver")
 
 
 class Tracer:
@@ -279,11 +406,14 @@ class Tracer:
     def tick(self, idx: int, t0: float, t1: float, *, k: int,
              active: int, emitted: int,
              kv_blocks_in_use: Optional[int] = None,
-             prefix_cached_pages: Optional[int] = None) -> None:
+             prefix_cached_pages: Optional[int] = None,
+             parts: Optional[Tuple[float, float, float]] = None) -> None:
         """One scheduler tick: recorded at the tick's existing sync point
         with host-side values only (occupancy counters come from the
-        engine's host mirrors, never a device read)."""
-        self._ticks.append((idx, t0, t1, k, active, emitted))
+        engine's host mirrors, never a device read). ``parts`` are the
+        serving-clock ends of its admit, dispatch and sync spans
+        (deliver runs from the last to ``t1``)."""
+        self._ticks.append((idx, t0, t1, k, active, emitted, parts))
         if kv_blocks_in_use is not None or prefix_cached_pages is not None:
             self._counters.append((t1, kv_blocks_in_use,
                                    prefix_cached_pages))
@@ -316,12 +446,20 @@ class Tracer:
         ]
         seen_slots = set()
         t_end = now()
-        for idx, t0, t1, k, active, emitted in ticks:
+        for idx, t0, t1, k, active, emitted, parts in ticks:
             ev.append({"ph": "X", "pid": pid, "tid": 0,
                        "name": f"tick {idx}", "cat": "scheduler",
                        "ts": us(t0), "dur": max(0.1, us(t1) - us(t0)),
                        "args": {"chunk_k": k, "active": active,
                                 "emitted": emitted}})
+            if parts is not None:
+                # the tick's children, nested in it on the same lane
+                edges = (t0, *parts, t1)
+                for name, a, b in zip(TICK_PARTS, edges, edges[1:]):
+                    ev.append({"ph": "X", "pid": pid, "tid": 0,
+                               "name": name, "cat": "scheduler",
+                               "ts": us(a), "dur": max(0.1, us(b) - us(a)),
+                               "args": {"tick": idx}})
         for ts, kv, pages in counters:
             if kv is not None:
                 ev.append({"ph": "C", "pid": pid, "tid": 0,

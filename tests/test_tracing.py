@@ -446,3 +446,43 @@ def test_phase_histograms_in_metrics(server):
     for fam in ("max_phase_queue_seconds", "max_phase_prefill_seconds",
                 "max_decode_per_token_seconds", "max_e2e_latency_seconds"):
         assert fam in joined, f"{fam} missing from {sorted(hists)[:8]}..."
+
+
+def test_trace_export_carries_tick_parts_and_a_clock_pair(server):
+    _run_job(server, "qwen3-4b", {"text": "parts", "max_new_tokens": 3})
+    code, body = _req(server, "GET", "/v2/trace/export")
+    assert code == 200
+    clocks = body["metadata"]["clocks"]
+    assert set(clocks) == {"serving_clock_s", "profiler_clock_ns"}
+    names = {e["name"] for e in body["traceEvents"] if e["ph"] == "X"}
+    assert {"max.sched.admit", "max.sched.dispatch", "max.sched.sync",
+            "max.sched.deliver"} <= names
+    # the pair is read now: the export's last tick lies before it
+    ticks = [e for e in body["traceEvents"]
+             if e["ph"] == "X" and e["name"].startswith("tick ")]
+    assert max(e["ts"] + e["dur"] for e in ticks) <= \
+        clocks["serving_clock_s"] * 1e6 + 1.0
+
+
+def test_stats_report_the_tick_split(server):
+    _run_job(server, "qwen3-4b", {"text": "split", "max_new_tokens": 3})
+    code, env = _req(server, "GET", "/v2/model/qwen3-4b/stats")
+    assert code == 200
+    s = env["service"]["scheduler"]
+    assert s["ticks"] >= 1 and s["inserts"] >= 1
+    assert s["host_s"] + s["sync_wait_s"] == pytest.approx(s["wall_s"],
+                                                           abs=2e-6)
+    assert 0 <= s["host_cpu_s"] <= s["host_s"] + 1e-6
+    assert s["insert_host_s"] > 0 and s["kv_tokens_sum"] > 0
+
+
+def test_metrics_report_gc_pauses(server):
+    import gc
+    gc.collect()          # a service is live: the collection is counted
+    code, m = _req(server, "GET", "/v2/metrics")
+    assert code == 200
+    gauges = m["metrics"]["gauges"]
+    pauses = {k: v for k, v in gauges.items()
+              if k.startswith("max_gc_pause_seconds_total")}
+    assert len(pauses) == 3
+    assert pauses['max_gc_pause_seconds_total{generation="2"}'] > 0
