@@ -27,6 +27,7 @@ from repro.kernels.decode_attention import decode_attention as _decode_pallas
 from repro.kernels.decode_attention import (
     paged_decode_attention as _paged_decode_pallas,
 )
+from repro.kernels.flash_attention import block_sizes as _flash_blocks
 from repro.kernels.flash_attention import flash_attention as _flash_pallas
 from repro.kernels.gmm import gmm as _gmm_pallas
 from repro.kernels.rglru import rglru_scan as _rglru_pallas
@@ -74,13 +75,13 @@ def _pad_to(x, axis: int, multiple: int, value=0.0):
     return jnp.pad(x, widths, constant_values=value), pad
 
 
-def flash_attention(q, k, v, *, causal=True, window=None, q_offset=0,
-                    bq=128, bkv=128):
+def flash_attention(q, k, v, *, causal=True, window=None, q_offset=0):
     """q [B, H, Sq, hd]; k, v [B, KV, Skv, hd] -> [B, H, Sq, hd]."""
     if get_backend() == "ref":
         return _ref.attention_ref(q, k, v, causal=causal, window=window,
                                   q_offset=q_offset)
-    Sq, Skv = q.shape[2], k.shape[2]
+    (_, H, Sq, hd), (KV, Skv) = q.shape, k.shape[1:3]
+    bq, bkv = _flash_blocks(Sq, Skv, H // KV, hd, window)
     qp, _ = _pad_to(q, 2, bq)
     kp, _ = _pad_to(k, 2, bkv)
     vp, _ = _pad_to(v, 2, bkv)

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
-from repro.kernels.flash_attention import flash_attention
+from repro.kernels.flash_attention import _kv_range, block_sizes, flash_attention
 from repro.kernels.decode_attention import decode_attention
 from repro.kernels.rglru import rglru_scan
 from repro.kernels.rwkv6 import wkv_scan
@@ -57,6 +57,86 @@ def test_flash_attention_padding_path():
             expect = ref.attention_ref(q, k, v, causal=causal)
             np.testing.assert_allclose(np.asarray(out), np.asarray(expect),
                                        atol=2e-5, rtol=1e-4)
+
+
+# (H, KV, Sq, Skv, hd, window, q_offset): the paths the grouped, block-
+# skipping kernel takes, each through ops.flash_attention's padding.
+FLASH_PATHS = {
+    "gqa8_hd128": (16, 2, 1024, 1024, 128, None, 0),   # deepseek grouping
+    "causal_1000": (4, 2, 1000, 1000, 64, None, 0),
+    "causal_1300": (4, 2, 1300, 1300, 64, None, 0),
+    "rect_q_offset": (4, 1, 300, 1000, 64, None, 700),
+    "window_multi_block": (4, 2, 1024, 1024, 64, 300, 0),
+    "short_16": (16, 2, 16, 16, 128, None, 0),
+}
+
+
+def _flash_inputs(case, dtype):
+    H, KV, Sq, Skv, hd, _, _ = FLASH_PATHS[case]
+    return (_rand((1, H, Sq, hd), 1, dtype), _rand((1, KV, Skv, hd), 2, dtype),
+            _rand((1, KV, Skv, hd), 3, dtype))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("case", sorted(FLASH_PATHS))
+def test_flash_attention_paths(case, dtype):
+    window, q_offset = FLASH_PATHS[case][5:]
+    q, k, v = _flash_inputs(case, dtype)
+    with ops.use_backend("interpret"):
+        out = ops.flash_attention(q, k, v, window=window, q_offset=q_offset)
+    expect = ref.attention_ref(q, k, v, window=window, q_offset=q_offset)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(expect, np.float32), **_tol(dtype))
+
+
+@pytest.mark.parametrize("case", sorted(FLASH_PATHS))
+def test_flash_attention_bf16_matches_f32_upcast(case):
+    """bf16 operands on the MXU give what the same values in f32 give."""
+    window, q_offset = FLASH_PATHS[case][5:]
+    q, k, v = _flash_inputs(case, jnp.bfloat16)
+    up = [x.astype(jnp.float32) for x in (q, k, v)]
+    with ops.use_backend("interpret"):
+        out = ops.flash_attention(q, k, v, window=window, q_offset=q_offset)
+        expect = ops.flash_attention(*up, window=window, q_offset=q_offset)
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(expect), **_tol(jnp.bfloat16))
+
+
+@pytest.mark.parametrize("Sq,Skv,G,hd,window,expect", [
+    (16, 16, 8, 128, None, (16, 128)),       # a short prompt is not padded up
+    (2048, 2048, 8, 128, None, (256, 1024)),  # deepseek-67b: 2048 q rows a step
+    (2048, 2048, 4, 128, None, (512, 1024)),  # qwen3-4b
+    (2048, 2048, 1, 256, None, (512, 1024)),
+    (1024, 1024, 2, 64, 300, (512, 384)),    # kv blocks follow the window
+])
+def test_flash_block_sizes(Sq, Skv, G, hd, window, expect):
+    assert block_sizes(Sq, Skv, G, hd, window) == expect
+
+
+@pytest.mark.parametrize("Sq,Skv,bq,bkv,window,q_offset,kv_len", [
+    (2048, 2048, 256, 512, None, 0, 2048),
+    (1536, 1536, 512, 512, None, 0, 1300),
+    (304, 1024, 16, 128, None, 700, 1000),
+    (1024, 1152, 512, 384, 300, 0, 1024),
+    (256, 512, 128, 128, 100, 256, 512),
+])
+def test_flash_kv_range_is_exactly_the_unmasked_blocks(Sq, Skv, bq, bkv,
+                                                       window, q_offset,
+                                                       kv_len):
+    """A q block computes a kv block iff the block holds an unmasked
+    position; every other step is skipped."""
+    q_pos = q_offset + np.arange(Sq)[:, None]
+    k_pos = np.arange(Skv)[None, :]
+    mask = (q_pos >= k_pos) & (k_pos < kv_len)
+    if window is not None:
+        mask &= (q_pos - k_pos) < window
+    for qi in range(Sq // bq):
+        lo, hi = _kv_range(qi, causal=True, window=window, bq=bq, bkv=bkv,
+                           q_offset=q_offset, kv_len=kv_len)
+        rows = mask[qi * bq:(qi + 1) * bq]
+        needed = [ki for ki in range(Skv // bkv)
+                  if rows[:, ki * bkv:(ki + 1) * bkv].any()]
+        assert list(range(int(lo), int(hi) + 1)) == needed
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

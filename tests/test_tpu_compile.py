@@ -100,12 +100,18 @@ def test_paged_decode_attention_compiles(one_chip):
     _fits(c)
 
 
-@pytest.mark.parametrize("S", [16, 128, 2048])
-def test_flash_prefill_compiles(one_chip, S):
+@pytest.mark.parametrize("model,S", [
+    *(pytest.param("qwen3-4b", S, id=str(S)) for S in (16, 128, 2048)),
+    *(pytest.param("deepseek-67b", S, id=f"deepseek-67b-{S}")
+      for S in (16, 1024, 2048))])
+def test_flash_prefill_compiles(one_chip, model, S):
+    """The blocks the kernel picks for each prompt size fit fast memory."""
+    cfg = ASSIGNED[model]
+    h, kv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     c = _compile(ops.flash_attention,
-                 _sds((1, H, S, HD), jnp.bfloat16, one_chip),
-                 _sds((1, KV, S, HD), jnp.bfloat16, one_chip),
-                 _sds((1, KV, S, HD), jnp.bfloat16, one_chip))
+                 _sds((1, h, S, hd), jnp.bfloat16, one_chip),
+                 _sds((1, kv, S, hd), jnp.bfloat16, one_chip),
+                 _sds((1, kv, S, hd), jnp.bfloat16, one_chip))
     assert "tpu_custom_call" in c.as_text()
     _fits(c)
 
